@@ -16,18 +16,7 @@ _SEP = "/"
 
 
 def _path_str(path) -> str:
-    try:
-        return jax.tree_util.keystr(path, simple=True, separator=_SEP)
-    except TypeError:  # jax < 0.5: keystr has no simple/separator kwargs
-        parts = []
-        for k in path:
-            for attr in ("key", "idx", "name"):
-                if hasattr(k, attr):
-                    parts.append(str(getattr(k, attr)))
-                    break
-            else:
-                parts.append(str(k))
-        return _SEP.join(parts)
+    return jax.tree_util.keystr(path, simple=True, separator=_SEP)
 
 
 def leaves_to_columns(tree) -> Dict[str, np.ndarray]:
@@ -101,11 +90,13 @@ def restore(lake: Lake, ref: str, *, mesh=None, param_specs=None,
     if mesh is not None and param_specs is not None:
         from ..distributed.sharding import named
 
-        shardings = named(mesh, param_specs)
-        params = jax.tree.map(jax.device_put, params, shardings)
+        params = jax.device_put(params, named(mesh, param_specs))
         if opt_state is not None and opt_specs is not None:
-            opt_state = jax.tree.map(jax.device_put, opt_state,
-                                     named(mesh, opt_specs))
+            opt_state = jax.device_put(opt_state, named(mesh, opt_specs))
+    else:
+        # placed once at load: a jitted step handed host arrays would copy
+        # them to the device again on every call
+        params = jax.device_put(params)
     if verify and "params_digest" in meta:
         actual = tree_digest_hex(params)
         if actual != meta["params_digest"]:

@@ -61,7 +61,7 @@ def mesh_fingerprint(mesh=None) -> Dict[str, Any]:
     if mesh is None:
         return {"kind": "unspecified"}
     return {
-        "kind": str(getattr(mesh.devices.flat[0], "device_kind", "cpu")),
+        "kind": str(mesh.devices.flat[0].device_kind),
         "shape": dict(mesh.shape),
         "axes": list(mesh.axis_names),
         "n_devices": int(mesh.devices.size),

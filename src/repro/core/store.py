@@ -312,32 +312,38 @@ class ObjectStore:
             codec = "zstd" if zstd is not None else "zlib"
         self.codec = codec
         self.level = level
-        if zstd is not None:
-            self._cctx = zstd.ZstdCompressor(level=level)
-            self._dctx = zstd.ZstdDecompressor()
-        else:
-            self._cctx = self._dctx = None
+        # a zstd context must not serve two threads at once (concurrent use
+        # corrupts frames or crashes): each thread reuses its own pair
+        self._zstd_ctx = threading.local()
         self._lock = threading.Lock()
 
     # ------------------------------------------------------------------ blobs
     def _path(self, digest: str) -> Path:
         return self.obj_dir / digest[:2] / digest[2:]
 
+    def _zstd(self):
+        """This thread's (compressor, decompressor)."""
+        ctx = getattr(self._zstd_ctx, "pair", None)
+        if ctx is None:
+            ctx = self._zstd_ctx.pair = (zstd.ZstdCompressor(level=self.level),
+                                         zstd.ZstdDecompressor())
+        return ctx
+
     def _encode(self, data: bytes) -> bytes:
         if not self.compress or len(data) <= 64 or self.codec == "raw":
             return _MAGIC + _CODEC_RAW + data
         if self.codec == "zstd":
-            return _MAGIC + _CODEC_ZSTD + self._cctx.compress(data)
+            return _MAGIC + _CODEC_ZSTD + self._zstd()[0].compress(data)
         # zstd levels reach 22 but zlib's cap is 9 — clamp so a store tuned
         # for zstd keeps working on a host that falls back to zlib
         return _MAGIC + _CODEC_ZLIB + zlib.compress(data, min(self.level, 9))
 
     def _decode(self, digest: str, payload: bytes) -> bytes:
-        if payload[4:5] == _CODEC_ZSTD and self._dctx is not None:
-            # hot path: reuse this store's decompressor across reads
+        if payload[4:5] == _CODEC_ZSTD and zstd is not None:
+            # hot path: reuse this thread's decompressor across reads
             if payload[:4] != _MAGIC:
                 raise ObjectNotFound(f"corrupt object {digest}")
-            return self._dctx.decompress(payload[5:])
+            return self._zstd()[1].decompress(payload[5:])
         return decode_frame(payload, what=f"object {digest}")
 
     def put(self, data: bytes) -> str:

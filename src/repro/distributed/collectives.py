@@ -21,8 +21,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-from .compat import axis_size, shard_map
-
 
 def hierarchical_psum(x: jnp.ndarray, *, intra_axis: str = "data",
                       inter_axis: str = "pod") -> jnp.ndarray:
@@ -32,7 +30,7 @@ def hierarchical_psum(x: jnp.ndarray, *, intra_axis: str = "data",
     hop moves only 1/|intra| of the tensor per device.
     Call INSIDE shard_map with both axes bound.
     """
-    n_intra = axis_size(intra_axis)
+    n_intra = jax.lax.axis_size(intra_axis)
     pad = (-x.shape[0]) % n_intra
     xp = jnp.pad(x, [(0, pad)] + [(0, 0)] * (x.ndim - 1)) if pad else x
     # reduce-scatter within the pod
@@ -56,8 +54,8 @@ def hierarchical_psum_int8(x: jnp.ndarray, residual: jnp.ndarray, *,
     quantization error is fed back into ``residual`` so it is re-applied on
     the next step (convergence-preserving — standard EF-SGD argument).
     """
-    n_intra = axis_size(intra_axis)
-    n_inter = axis_size(inter_axis)
+    n_intra = jax.lax.axis_size(intra_axis)
+    n_inter = jax.lax.axis_size(inter_axis)
     pad = (-x.shape[0]) % n_intra
     xp = jnp.pad(x, [(0, pad)] + [(0, 0)] * (x.ndim - 1)) if pad else x
     shard = jax.lax.psum_scatter(xp, intra_axis, scatter_dimension=0,
@@ -71,7 +69,7 @@ def hierarchical_psum_int8(x: jnp.ndarray, residual: jnp.ndarray, *,
     scale = jnp.maximum(jnp.max(jnp.abs(g)) / 127.0, 1e-12)
     scale = jax.lax.pmax(scale, inter_axis)
     q = jnp.clip(jnp.round(g / scale), -127, 127).astype(jnp.int8)
-    n_inter = axis_size(inter_axis)
+    n_inter = jax.lax.axis_size(inter_axis)
     if n_inter == 2:
         # pairwise exchange: the wire carries TRUE int8 payloads (psum
         # would upcast before transfer); sum locally after the swap
@@ -106,6 +104,6 @@ def make_hierarchical_grad_reducer(mesh: Mesh, *, compress: bool = False):
         return jax.tree.map(one, grads)
 
     in_specs = P(("pod", "data"))
-    return shard_map(reduce_tree, mesh=mesh,
-                     in_specs=in_specs, out_specs=in_specs,
-                     check_vma=False)
+    return jax.shard_map(reduce_tree, mesh=mesh,
+                         in_specs=in_specs, out_specs=in_specs,
+                         check_vma=False)

@@ -7,24 +7,33 @@ Used by the checkpoint layer to content-address device-resident tensors
 
 from __future__ import annotations
 
-import functools
-
 import jax
 import jax.numpy as jnp
 import numpy as np
 
+from .. import interpret_mode
 from . import ref
 from .kernel import fingerprint
 
 
-@functools.partial(jax.jit, static_argnames=("interpret",))
-def tensor_digest(arr: jnp.ndarray, *, interpret: bool = True) -> jnp.ndarray:
-    """(8,) uint32 digest of one array (kernel path)."""
-    return fingerprint(arr, interpret=interpret)
+@jax.jit
+def _digest(arr: jnp.ndarray) -> jnp.ndarray:
+    return fingerprint(arr, interpret=interpret_mode())
+
+
+def tensor_digest(arr) -> jnp.ndarray:
+    """(8,) uint32 digest of one array (kernel path).  The compiler cannot
+    partition a Pallas kernel, so an array sharded over several devices is
+    first gathered onto one of them."""
+    arr = jnp.asarray(arr)
+    devices = arr.sharding.device_set
+    if len(devices) > 1:
+        arr = jax.device_put(arr, min(devices, key=lambda d: d.id))
+    return _digest(arr)
 
 
 def tensor_digest_hex(arr) -> str:
-    return ref.digest_hex(tensor_digest(jnp.asarray(arr)))
+    return ref.digest_hex(tensor_digest(arr))
 
 
 def tree_digest_hex(tree) -> str:
@@ -32,6 +41,6 @@ def tree_digest_hex(tree) -> str:
     leaves = jax.tree_util.tree_leaves_with_path(tree)
     parts = []
     for path, leaf in sorted(leaves, key=lambda kv: jax.tree_util.keystr(kv[0])):
-        parts.append(np.asarray(tensor_digest(jnp.asarray(leaf))))
+        parts.append(np.asarray(tensor_digest(leaf)))
     stacked = jnp.asarray(np.concatenate(parts).astype(np.uint32))
     return ref.digest_hex(ref.fingerprint_ref(stacked))

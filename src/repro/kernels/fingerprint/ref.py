@@ -35,12 +35,15 @@ def _to_words(arr: jnp.ndarray) -> jnp.ndarray:
     if flat.dtype.kind != "u":
         flat = jax.lax.bitcast_convert_type(flat, uint)
     if nbits < 32:
+        # strided slices, not a (-1, per) reshape: a trailing dim of 2 or 4
+        # pads to a full 128-lane tile on TPU (64x the bytes for bf16)
         per = 32 // nbits
         pad = (-flat.shape[0]) % per
         flat = jnp.pad(flat, (0, pad))
-        w = flat.reshape(-1, per).astype(jnp.uint32)
-        shifts = (jnp.arange(per, dtype=jnp.uint32) * nbits)
-        return jnp.sum(w << shifts[None, :], axis=1, dtype=jnp.uint32)
+        w = flat[0::per].astype(jnp.uint32)
+        for j in range(1, per):
+            w = w | (flat[j::per].astype(jnp.uint32) << np.uint32(j * nbits))
+        return w
     if nbits == 64:
         lo = flat.astype(jnp.uint32)
         hi = (flat >> np.uint64(32)).astype(jnp.uint32)

@@ -14,32 +14,31 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 
+from .. import interpret_mode
 from . import ref
 from .kernel import flash_attention
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6))
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5))
 def flash_gqa(q, k, v, causal: bool = True, window: Optional[int] = None,
-              softcap: Optional[float] = None, interpret: bool = True):
+              softcap: Optional[float] = None):
     """q: (B, S, Hq, d); k/v: (B, T, Hkv, d) → (B, S, Hq, d).
 
-    interpret=True by default: this container is CPU-only; on TPU the caller
-    passes interpret=False.
-    """
+    The kernel compiles on a TPU and runs interpreted elsewhere."""
     qt = jnp.swapaxes(q, 1, 2)
     kt = jnp.swapaxes(k, 1, 2)
     vt = jnp.swapaxes(v, 1, 2)
     out = flash_attention(qt, kt, vt, causal=causal, window=window,
-                          softcap=softcap, interpret=interpret)
+                          softcap=softcap, interpret=interpret_mode())
     return jnp.swapaxes(out, 1, 2)
 
 
-def _fwd(q, k, v, causal, window, softcap, interpret):
-    out = flash_gqa(q, k, v, causal, window, softcap, interpret)
+def _fwd(q, k, v, causal, window, softcap):
+    out = flash_gqa(q, k, v, causal, window, softcap)
     return out, (q, k, v)
 
 
-def _bwd(causal, window, softcap, interpret, res, g):
+def _bwd(causal, window, softcap, res, g):
     """Recompute-based backward via the reference implementation — the
     canonical flash-bwd trade (no O(S·T) tensor is saved from the fwd)."""
     q, k, v = res

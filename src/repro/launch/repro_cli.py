@@ -395,8 +395,10 @@ def main(argv=None):
         return
     if args.cmd == "serve" and args.replicas:
         from repro.configs import full_config, smoke_config
+        from repro.launch.compile_cache import enable_compile_cache
         from repro.launch.serve import run_fleet
 
+        enable_compile_cache()
         cfg = (smoke_config(args.arch) if args.smoke
                else full_config(args.arch))
         fleet = run_fleet(Lake(args.lake), cfg, replicas=args.replicas,
@@ -619,7 +621,9 @@ def main(argv=None):
         target = latest_checkpoint(lake, args.to_ref) or args.to_ref
         if args.canary:
             from repro.configs import full_config, smoke_config
+            from repro.launch.compile_cache import enable_compile_cache
 
+            enable_compile_cache()
             cfg = (smoke_config(args.arch) if args.smoke
                    else full_config(args.arch))
             rng = np.random.default_rng(0)

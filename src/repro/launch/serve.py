@@ -24,6 +24,7 @@ import numpy as np
 
 from repro.configs import full_config, smoke_config
 from repro.core import Lake
+from repro.launch.compile_cache import enable_compile_cache
 from repro.serving import (BatchedServer, FixedBatchedServer, ServeEngine,
                            ServingFleet)
 
@@ -88,6 +89,7 @@ def main():
     ap.add_argument("--watch-tag", default="serving/prod")
     ap.add_argument("--poll-every", type=int, default=4)
     args = ap.parse_args()
+    enable_compile_cache()
 
     cfg = smoke_config(args.arch) if args.smoke else full_config(args.arch)
     lake = Lake(args.lake)

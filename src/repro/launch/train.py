@@ -12,6 +12,7 @@ import argparse
 from repro.configs import full_config, smoke_config
 from repro.core import Lake
 from repro.data import build_data_pipeline, seed_corpus
+from repro.launch.compile_cache import enable_compile_cache
 from repro.runtime import Trainer, TrainerConfig
 
 
@@ -33,6 +34,7 @@ def main():
     ap.add_argument("--schedule", default="cosine",
                     choices=["cosine", "wsd", "constant"])
     args = ap.parse_args()
+    enable_compile_cache()
 
     cfg = smoke_config(args.arch) if args.smoke else full_config(args.arch)
     lake = Lake(args.lake)

@@ -202,8 +202,7 @@ def moe_layer_ep(
         return (y.reshape(B_loc, S, D), aux.reshape(1),
                 dropped.reshape(1))
 
-    from ..distributed.compat import shard_map
-    y, aux, dropped = shard_map(
+    y, aux, dropped = jax.shard_map(
         local, mesh=mesh,
         in_specs=(P(dp_spec, None, None), P(None, None),
                   P(ep_axis, None, None), P(ep_axis, None, None),

@@ -98,13 +98,14 @@ class Trainer:
         commit = ckpt.latest_checkpoint(self.lake, self.run_branch)
         if commit is None:
             return self.init_state()
-        params, opt_cols, meta = ckpt.restore(self.lake, commit)
-        template = adamw.init(params, self.tcfg.opt)
-        tables = self.lake.catalog.tables(commit)
-        opt_state = template
-        if "ckpt_opt" in tables:
-            cols = self.lake.io.read(tables["ckpt_opt"])
-            opt_state = ckpt.restore_into(template, cols)
+        params, opt_tree, meta = ckpt.restore(self.lake, commit)
+        if opt_tree is None:
+            opt_state = adamw.init(params, self.tcfg.opt)
+        else:  # the saved leaves, typed by the optimizer's own structure
+            template = jax.eval_shape(
+                lambda p: adamw.init(p, self.tcfg.opt), params)
+            opt_state = jax.device_put(ckpt.restore_into(
+                template, ckpt.leaves_to_columns(opt_tree)))
         return params, opt_state, int(meta["step"])
 
     # ----------------------------------------------------------------- loop

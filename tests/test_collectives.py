@@ -18,9 +18,9 @@ _PROG = textwrap.dedent("""\
     from jax.sharding import PartitionSpec as P
     from repro.distributed.collectives import (hierarchical_psum,
                                                hierarchical_psum_int8)
-    from repro.distributed.compat import shard_map
+    from repro.launch.mesh import auto_mesh
 
-    mesh = jax.make_mesh((2, 4), ("pod", "data"))
+    mesh = auto_mesh((2, 4), ("pod", "data"))
     x = jnp.arange(512, dtype=jnp.float32).reshape(64, 8) / 7.0
 
     def flat_sum(v):
@@ -29,9 +29,9 @@ _PROG = textwrap.dedent("""\
     def hier_sum(v):
         return hierarchical_psum(v, intra_axis="data", inter_axis="pod")
 
-    sm = lambda f: shard_map(f, mesh=mesh, in_specs=P(("pod", "data")),
-                             out_specs=P(("pod", "data")),
-                             check_vma=False)
+    sm = lambda f: jax.shard_map(f, mesh=mesh, in_specs=P(("pod", "data")),
+                                 out_specs=P(("pod", "data")),
+                                 check_vma=False)
     a = jax.jit(sm(flat_sum))(x)
     b = jax.jit(sm(hier_sum))(x)
     exact = float(jnp.max(jnp.abs(a - b)))

@@ -13,6 +13,7 @@ from jax.sharding import PartitionSpec as P
 
 from repro.configs import full_config
 from repro.distributed import analysis
+from repro.launch.mesh import auto_mesh
 from repro.models import init_params
 
 
@@ -90,7 +91,7 @@ def test_roofline_terms_and_bottleneck():
 def test_param_specs_match_tree():
     from repro.distributed import param_specs
     cfg = full_config("qwen3-moe-235b-a22b")
-    mesh = jax.make_mesh((1, 1), ("data", "model"))
+    mesh = auto_mesh((1, 1), ("data", "model"))
     specs = param_specs(cfg, mesh)
     params = jax.eval_shape(
         lambda: init_params(cfg, jax.random.PRNGKey(0)))
@@ -101,7 +102,7 @@ def test_param_specs_match_tree():
 
 def test_ep_fallback_for_non_divisible_experts():
     from repro.distributed import param_specs
-    mesh16 = jax.make_mesh((1, 1), ("data", "model"))
+    mesh16 = auto_mesh((1, 1), ("data", "model"))
     # qwen2-moe: 60 experts — with |model|=16 EP doesn't divide.
     # At mesh (1,1) everything is unsharded; the policy is pure logic,
     # so call it with synthetic axis sizes via a fake mesh is complex —
@@ -122,12 +123,13 @@ _SUBPROC = textwrap.dedent("""\
     from repro.configs import smoke_config
     from repro.distributed import (batch_specs, named, opt_state_specs,
                                    param_specs, make_activation_constraint)
+    from repro.launch.mesh import auto_mesh
     from repro.models import init_params
     from repro.optim import adamw
     from repro.runtime.steps import build_train_step
 
     cfg = smoke_config("{arch}").with_(attn_block=16)
-    mesh = jax.make_mesh((4, 2), ("data", "model"))
+    mesh = auto_mesh((4, 2), ("data", "model"))
     pspecs = param_specs(cfg, mesh)
     ac = make_activation_constraint(cfg, mesh)
     params = jax.eval_shape(lambda: init_params(cfg, jax.random.PRNGKey(0)))

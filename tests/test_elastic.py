@@ -19,6 +19,7 @@ _PROG = textwrap.dedent("""\
     from repro.configs import smoke_config
     from repro.core import Lake
     from repro.distributed import param_specs, named
+    from repro.launch.mesh import auto_mesh
     from repro.models import init_params, forward
 
     cfg = smoke_config("paper-demo")
@@ -28,7 +29,7 @@ _PROG = textwrap.dedent("""\
         lake.catalog.create_branch("t.run", "main", author="t")
 
     # "train" on an 8-device (4 data × 2 model) mesh and checkpoint
-    mesh_a = jax.make_mesh((4, 2), ("data", "model"))
+    mesh_a = auto_mesh((4, 2), ("data", "model"))
     specs_a = param_specs(cfg, mesh_a)
     p_a = jax.tree.map(jax.device_put, params, named(mesh_a, specs_a))
     commit = save(lake, "t.run", step=1, params=p_a, author="t")

@@ -170,6 +170,9 @@ def test_sibling_failure_drains_in_flight_without_publishing(seeded_lake):
 
     @model()
     def fail_fast(data=Model("source_table")):
+        # fail only once the sibling is in flight: on a loaded host the
+        # failure could otherwise be observed before `slow` ever started
+        slow_ran.wait(timeout=10)
         raise RuntimeError("fast failure")
 
     @model()
