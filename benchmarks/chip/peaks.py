@@ -1,0 +1,18 @@
+"""Peaks of one chip, keyed by JAX's ``device_kind``.
+
+Source: Google Cloud documentation, "TPU v5e" (per chip: 197 TFLOP/s in
+bfloat16, 393 TOP/s in int8, 16 GB of HBM at 819 GB/s).  A device that is
+not in the table is an error, never a default.
+"""
+
+PEAKS = {
+    "TPU v5 lite": {"bf16_flops": 197e12, "int8_ops": 393e12,
+                    "hbm_bytes_per_s": 819e9, "hbm_bytes": 16e9},
+}
+
+
+def peaks(device_kind: str) -> dict:
+    if device_kind not in PEAKS:
+        raise KeyError(f"no peaks for device kind {device_kind!r}; known: "
+                       f"{sorted(PEAKS)}")
+    return PEAKS[device_kind]
