@@ -31,13 +31,11 @@ import json
 import os
 import sys
 import tempfile
-import time
 
 import numpy as np
 
 ROOT = os.path.dirname(os.path.abspath(__file__))
 SEED = 0
-BACKEND_COMPILE = "/jax/core/compile/backend_compile_duration"
 
 # Prefill logits vs the float32 reference.  Bound set from the worst case
 # that a float32 matmul at default precision on a TPU is one bf16 pass
@@ -87,33 +85,33 @@ def rel_l2(got, want) -> float:
 
 
 class Phases:
-    """Prints one line per phase with its compiles, from JAX's own compile
-    events (``fun_name`` says which jitted function compiled)."""
+    """Prints one line per phase with its compiles, read from the program's
+    ``jax.compile`` spans (``fun_name`` says which jitted function
+    compiled).  Each phase is a span, so its compiles are recorded."""
 
-    def __init__(self, jax):
-        self.events = []  # (fun_name, seconds)
-        jax.monitoring.register_event_duration_secs_listener(self._on_event)
+    @staticmethod
+    def compiles(since: float, fun_name=None) -> list:
+        from repro import tracing
 
-    def _on_event(self, event, duration, **kw):
-        if event == BACKEND_COMPILE:
-            self.events.append((kw.get("fun_name", "?"), duration))
-
-    def compiles_since(self, mark: int, fun_name=None) -> int:
-        return sum(1 for f, _ in self.events[mark:]
-                   if fun_name is None or f == fun_name)
+        return [s for s in tracing.spans() if s.name == tracing.COMPILE
+                and s.t0 >= since
+                and fun_name in (None, s.attrs["fun_name"])]
 
     @contextlib.contextmanager
     def phase(self, name: str):
-        mark, t0 = len(self.events), time.perf_counter()
-        try:
-            yield mark
-        except BaseException as e:
-            print(f"[{name}] FAILED: {type(e).__name__}: {e}", flush=True)
-            raise
-        secs = sum(s for _, s in self.events[mark:])
-        print(f"[{name}] ok: {len(self.events) - mark} compiles, "
-              f"{secs:.1f} s compiling, "
-              f"{time.perf_counter() - t0:.1f} s in the phase", flush=True)
+        from repro import tracing
+
+        with tracing.span("smoke." + name) as span:
+            try:
+                yield span.t0
+            except BaseException as e:
+                print(f"[{name}] FAILED: {type(e).__name__}: {e}",
+                      flush=True)
+                raise
+        done = self.compiles(span.t0)
+        secs = sum(s.t1 - s.t0 for s in done)
+        print(f"[{name}] ok: {len(done)} compiles, {secs:.1f} s compiling, "
+              f"{span.elapsed():.1f} s in the phase", flush=True)
 
 
 # ----------------------------------------------------------------- phases
@@ -258,7 +256,7 @@ def serve(lake, cfg, sz: Sizes, commit, phases: Phases, mark: int):
     check(sorted(server.completed) == list(range(len(sz.requests))),
           f"completed {sorted(server.completed)}")
     lengths = sorted({p for p, _ in sz.requests})
-    n_prefill = phases.compiles_since(mark, "jit(prefill_tok)")
+    n_prefill = len(phases.compiles(mark, "jit(prefill_tok)"))
     print(f"  prefill compiles: {n_prefill} for {len(lengths)} distinct "
           f"prompt lengths {lengths}")
 
@@ -389,7 +387,7 @@ def run(chips: int, cfg=None, sz: Sizes = Sizes()) -> dict:
     print(f"[device] {devs[0].platform} {devs[0].device_kind} x{len(devs)}; "
           f"jax {jax.__version__}; compile cache {cache_dir}; model "
           f"{cfg.name} ({cfg.param_count() / 1e6:.1f}M params)", flush=True)
-    phases = Phases(jax)
+    phases = Phases()
     with tempfile.TemporaryDirectory(prefix="chip_smoke_") as tmp:
         lake = Lake(os.path.join(tmp, "lake"))
         with phases.phase("data"):

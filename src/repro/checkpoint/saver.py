@@ -9,6 +9,7 @@ from typing import Any, Dict, Optional, Tuple
 import jax
 import numpy as np
 
+from .. import tracing
 from ..core import Lake
 from ..kernels.fingerprint.ops import tree_digest_hex
 
@@ -58,17 +59,29 @@ def save(lake: Lake, branch: str, *, step: int, params, opt_state=None,
          author: str = "system", extra_meta: Optional[dict] = None,
          digest: bool = True) -> str:
     """Commit a checkpoint (one multi-table transaction). Returns commit."""
-    updates = {"ckpt_params": lake.io.write_snapshot(
-        leaves_to_columns(params))}
-    if opt_state is not None:
-        updates["ckpt_opt"] = lake.io.write_snapshot(
-            leaves_to_columns(opt_state))
-    meta = {"step": int(step), **(extra_meta or {})}
-    if digest:
-        # device-side content digest (fingerprint kernel) — integrity check
-        meta["params_digest"] = tree_digest_hex(params)
-    return lake.catalog.commit(branch, updates, f"checkpoint step={step}",
-                               author=author, meta={"checkpoint": meta})
+    with tracing.span("ckpt.save", step=int(step)):
+        updates = {"ckpt_params": lake.io.write_snapshot(
+            _host_columns(params))}
+        if opt_state is not None:
+            updates["ckpt_opt"] = lake.io.write_snapshot(
+                _host_columns(opt_state))
+        meta = {"step": int(step), **(extra_meta or {})}
+        if digest:
+            # device-side content digest (fingerprint kernel): integrity check
+            with tracing.span("ckpt.digest",
+                              leaves=len(jax.tree_util.tree_leaves(params))):
+                meta["params_digest"] = tree_digest_hex(params)
+        return lake.catalog.commit(branch, updates,
+                                   f"checkpoint step={step}", author=author,
+                                   meta={"checkpoint": meta})
+
+
+def _host_columns(tree) -> Dict[str, np.ndarray]:
+    """``leaves_to_columns`` as a span: the device→host copy of a tree."""
+    with tracing.span("ckpt.copy") as s:
+        cols = leaves_to_columns(tree)
+        s.set(leaves=len(cols), bytes=sum(a.nbytes for a in cols.values()))
+    return cols
 
 
 def restore(lake: Lake, ref: str, *, mesh=None, param_specs=None,

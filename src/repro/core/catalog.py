@@ -37,6 +37,7 @@ from typing import Any, Callable, Dict, List, Mapping, Optional, Sequence, Set
 
 import msgpack
 
+from .. import tracing
 from .contracts import (CONTRACTS_TABLE, Contract, Rule, evaluate,
                         pack_contracts, unpack_contracts)
 from .errors import (ContractViolation, MergeConflict, ObjectNotFound,
@@ -259,6 +260,7 @@ class Catalog:
     def delete_tag(self, name: str) -> None:
         self.store.delete_ref(_TAG_PREFIX + name)
 
+    @tracing.span("catalog.commit")
     def commit(
         self,
         branch: str,

@@ -43,6 +43,7 @@ try:  # POSIX file locking for cross-process CAS; absent on Windows
 except ImportError:  # pragma: no cover - non-POSIX fallback: thread lock only
     fcntl = None
 
+from .. import tracing
 from .errors import (AmbiguousRefUpdate, CodecUnavailable, ObjectNotFound,
                      RefConflict, RefNotFound)
 
@@ -347,21 +348,28 @@ class ObjectStore:
         return decode_frame(payload, what=f"object {digest}")
 
     def put(self, data: bytes) -> str:
-        digest = sha256_hex(data)
-        path = self._path(digest)
-        if path.exists():  # dedup: content addressing makes re-puts free
+        with tracing.span("store.hash", bytes=len(data)) as s:
+            digest = sha256_hex(data)
+            path = self._path(digest)
+            # dedup: content addressing makes re-puts free
+            dedup = path.exists()
+            s.set(dedup=dedup)
+        if dedup:
             return digest
-        payload = self._encode(data)
-        path.parent.mkdir(parents=True, exist_ok=True)
-        # Write-then-rename so readers never observe partial objects.
-        fd, tmp = tempfile.mkstemp(dir=path.parent, prefix=".tmp-")
-        try:
-            with os.fdopen(fd, "wb") as f:
-                f.write(payload)
-            os.replace(tmp, path)
-        finally:
-            if os.path.exists(tmp):
-                os.unlink(tmp)
+        with tracing.span("store.compress", bytes=len(data)) as s:
+            payload = self._encode(data)
+            s.set(bytes_out=len(payload))
+        with tracing.span("store.write", bytes=len(payload)):
+            path.parent.mkdir(parents=True, exist_ok=True)
+            # Write-then-rename so readers never observe partial objects.
+            fd, tmp = tempfile.mkstemp(dir=path.parent, prefix=".tmp-")
+            try:
+                with os.fdopen(fd, "wb") as f:
+                    f.write(payload)
+                os.replace(tmp, path)
+            finally:
+                if os.path.exists(tmp):
+                    os.unlink(tmp)
         return digest
 
     def get(self, digest: str) -> bytes:

@@ -16,6 +16,7 @@ from typing import Any, Dict, List, Mapping, Optional, Sequence, Tuple
 import msgpack
 import numpy as np
 
+from .. import tracing
 from .errors import SchemaError
 
 try:  # bfloat16 & friends come with jax
@@ -111,25 +112,31 @@ def encode(cols: Mapping[str, np.ndarray]) -> Tuple[bytes, Dict[str, Any]]:
     becomes the manifest entry next to the content digest."""
     if not cols:
         raise SchemaError("tensorfile needs at least one column")
-    arrays = {k: np.ascontiguousarray(np.asarray(v)) for k, v in cols.items()}
-    nrows = {v.shape[0] for v in arrays.values()}
-    if len(nrows) != 1:
-        raise SchemaError(f"ragged columns: row counts {sorted(nrows)}")
-    (n,) = nrows
-    schema = Schema.of(arrays)
-    payload = {
-        "v": _FORMAT_VERSION,
-        "nrows": n,
-        "schema": schema.to_obj(),
-        "data": {k: arrays[k].tobytes() for k in sorted(arrays)},
-    }
-    blob = msgpack.packb(payload, use_bin_type=True)
-    meta = {
-        "nrows": n,
-        "schema": schema.to_obj(),
-        "stats": {k: _column_stats(arrays[k]) for k in sorted(arrays)},
-        "nbytes": sum(a.nbytes for a in arrays.values()),
-    }
+    with tracing.span("table.encode") as s:
+        arrays = {k: np.ascontiguousarray(np.asarray(v))
+                  for k, v in cols.items()}
+        nrows = {v.shape[0] for v in arrays.values()}
+        if len(nrows) != 1:
+            raise SchemaError(f"ragged columns: row counts {sorted(nrows)}")
+        (n,) = nrows
+        schema = Schema.of(arrays)
+        with tracing.span("table.pack"):
+            payload = {
+                "v": _FORMAT_VERSION,
+                "nrows": n,
+                "schema": schema.to_obj(),
+                "data": {k: arrays[k].tobytes() for k in sorted(arrays)},
+            }
+            blob = msgpack.packb(payload, use_bin_type=True)
+        with tracing.span("table.stats"):
+            stats = {k: _column_stats(arrays[k]) for k in sorted(arrays)}
+        meta = {
+            "nrows": n,
+            "schema": schema.to_obj(),
+            "stats": stats,
+            "nbytes": sum(a.nbytes for a in arrays.values()),
+        }
+        s.set(bytes=len(blob))
     return blob, meta
 
 

@@ -20,7 +20,6 @@ slice exclusion; in simulation it is observability + a tested interface).
 
 from __future__ import annotations
 
-import time
 from dataclasses import dataclass, field
 from typing import Any, Callable, Dict, List, Optional
 
@@ -28,6 +27,7 @@ import jax
 import numpy as np
 
 from .. import checkpoint as ckpt
+from .. import tracing
 from ..core import Lake, Pipeline, not_empty, no_nans, publish
 from ..core.wap import Expectation
 from ..data.loader import DeterministicLoader
@@ -117,20 +117,24 @@ class Trainer:
             if self.failure_at is not None and step == self.failure_at:
                 self.failure_at = None  # next attempt survives
                 raise RuntimeError(f"injected node failure at step {step}")
-            t0 = time.perf_counter()
-            batch = {"tokens": jax.numpy.asarray(
-                self.loader.batch(step)["tokens"])}
-            params, opt_state, metrics = self.train_step(params, opt_state,
-                                                         batch)
-            loss = float(metrics["loss"])
-            wall = (time.perf_counter() - t0) * 1e3
-            median_tracker.append(wall)
-            med = float(np.median(median_tracker[-32:]))
-            straggler = len(median_tracker) > 4 and \
-                wall > self.tcfg.straggler_factor * med
-            if straggler:
-                self.straggler_events += 1
-            self.records.append(StepRecord(step, loss, wall, straggler))
+            with tracing.span("train.step", step=step) as span:
+                with tracing.span("train.feed") as feed:
+                    tokens = self.loader.batch(step)["tokens"]
+                    batch = {"tokens": jax.numpy.asarray(tokens)}
+                    feed.set(bytes=int(tokens.nbytes))
+                with tracing.span("train.dispatch"):
+                    params, opt_state, metrics = self.train_step(
+                        params, opt_state, batch)
+                with tracing.span("train.sync"):
+                    loss = float(metrics["loss"])
+                wall = span.elapsed() * 1e3
+                median_tracker.append(wall)
+                med = float(np.median(median_tracker[-32:]))
+                straggler = len(median_tracker) > 4 and \
+                    wall > self.tcfg.straggler_factor * med
+                if straggler:
+                    self.straggler_events += 1
+                self.records.append(StepRecord(step, loss, wall, straggler))
 
             if (step + 1) % self.tcfg.ckpt_every == 0 \
                     or step + 1 == self.tcfg.n_steps:

@@ -32,12 +32,14 @@ subsumes, both pinned by ``tests/serving_conformance.py``:
 
 from __future__ import annotations
 
+import time
 from dataclasses import dataclass, field
 from typing import Dict, List, Optional
 
 import jax.numpy as jnp
 import numpy as np
 
+from .. import tracing
 from ..models import init_cache
 from .engine import GenerationResult, Request, ServeEngine
 
@@ -72,6 +74,7 @@ class ContinuousBatcher:
         self._decode = engine.row_decode()
         self._log: Dict[int, jnp.ndarray] = {}       # interval -> (S,1) toks
         self._np_log: Dict[int, np.ndarray] = {}     # ...materialized
+        self._submitted: Dict[int, float] = {}       # request id -> when
         #: decode intervals run so far (the bench's unit of rollout blip)
         self.intervals = 0
 
@@ -81,6 +84,7 @@ class ContinuousBatcher:
         assert prompt.shape[0] + n_tokens <= self.engine.max_len
         self.queue.append(Request(request_id, np.asarray(prompt, np.int32),
                                   n_tokens))
+        self._submitted[request_id] = time.perf_counter()
 
     @property
     def pending(self) -> int:
@@ -99,6 +103,7 @@ class ContinuousBatcher:
         self._free = list(range(self.slots))
         self._log.clear()
         self._np_log.clear()
+        self._submitted.clear()
         return out
 
     # ---------------------------------------------------------------- step
@@ -113,8 +118,9 @@ class ContinuousBatcher:
         if a is None:
             lo = interval - interval % self._CHUNK
             span = [j for j in range(lo, lo + self._CHUNK) if j in self._log]
-            block = np.asarray(jnp.concatenate([self._log[j] for j in span],
-                                               axis=1))
+            with tracing.span("serve.fetch", intervals=len(span)):
+                block = np.asarray(jnp.concatenate(
+                    [self._log[j] for j in span], axis=1))
             for col, j in enumerate(span):
                 self._np_log[j] = block[:, col:col + 1]
             a = self._np_log[interval]
@@ -143,38 +149,48 @@ class ContinuousBatcher:
         done = 0
         while self._free and self.queue:
             r = self.queue.pop(0)
-            first_tok, cache1 = self.engine.prefill_one(r.prompt)
-            inflight = _InFlight(r, first_tok)
-            if r.n_tokens == 1:  # completed at prefill; slot stays free
-                self._complete(inflight, None)
-                done += 1
-                continue
-            slot = self._free.pop(0)
-            self._cache, self._tokens = self.engine.write_slot(
-                self._cache, self._tokens, cache1, first_tok, slot)
-            self._active[slot] = inflight
+            with tracing.span("serve.admit", rid=r.request_id,
+                              prompt_len=int(r.prompt.shape[0])) as s:
+                submitted = self._submitted.pop(r.request_id, None)
+                if submitted is not None:  # the wait in the queue
+                    tracing.record("serve.queue", submitted, s.t0,
+                                   rid=r.request_id)
+                first_tok, cache1 = self.engine.prefill_one(r.prompt)
+                inflight = _InFlight(r, first_tok)
+                if r.n_tokens == 1:  # completed at prefill; slot stays free
+                    self._complete(inflight, None)
+                    done += 1
+                    continue
+                slot = self._free.pop(0)
+                self._cache, self._tokens = self.engine.write_slot(
+                    self._cache, self._tokens, cache1, first_tok, slot)
+                self._active[slot] = inflight
         return done
 
     def step(self) -> int:
         """One admit + decode interval; returns requests completed."""
-        done = self._admit()
-        if not self._active:
+        with tracing.span("serve.step") as s:
+            done = self._admit()
+            s.set(active=len(self._active))
+            if not self._active:
+                return done
+            self.intervals += 1
+            s.set(interval=self.intervals)
+            with tracing.span("serve.decode"):
+                self._tokens, self._cache = self._decode(
+                    self.engine.params, self._tokens, self._cache)
+            self._log[self.intervals] = self._tokens
+            for slot in sorted(self._active):
+                inflight = self._active[slot]
+                inflight.ivals.append(self.intervals)
+                if 1 + len(inflight.ivals) >= inflight.request.n_tokens:
+                    self._complete(inflight, slot)
+                    del self._active[slot]
+                    self._free.append(slot)
+                    done += 1
+            if done or not self.intervals % 64:
+                self._prune_log()
             return done
-        self.intervals += 1
-        self._tokens, self._cache = self._decode(
-            self.engine.params, self._tokens, self._cache)
-        self._log[self.intervals] = self._tokens
-        for slot in sorted(self._active):
-            inflight = self._active[slot]
-            inflight.ivals.append(self.intervals)
-            if 1 + len(inflight.ivals) >= inflight.request.n_tokens:
-                self._complete(inflight, slot)
-                del self._active[slot]
-                self._free.append(slot)
-                done += 1
-        if done or not self.intervals % 64:
-            self._prune_log()
-        return done
 
 
 class BatchedServer(ContinuousBatcher):
