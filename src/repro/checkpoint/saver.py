@@ -8,6 +8,7 @@ from typing import Any, Dict, Optional, Tuple
 
 import jax
 import numpy as np
+from jax.experimental.layout import Format, Layout
 
 from .. import tracing
 from ..core import Lake
@@ -20,12 +21,26 @@ def _path_str(path) -> str:
     return jax.tree_util.keystr(path, simple=True, separator=_SEP)
 
 
+def host_copy(leaf) -> np.ndarray:
+    """A leaf on the host, in row-major order.
+
+    A TPU may hold an array with its axes in another order (the one that
+    pads its tiles least), and the host copy keeps that order: the
+    tensorfile would then have to transpose it on the host, which is
+    slow.  Such a leaf is laid out row-major on the device first."""
+    layout = getattr(getattr(leaf, "format", None), "layout", None)
+    order = tuple(range(np.ndim(leaf)))
+    if layout is not None and layout.major_to_minor != order:
+        leaf = jax.device_put(leaf, Format(Layout(major_to_minor=order),
+                                           leaf.sharding))
+    return np.asarray(leaf)
+
+
 def leaves_to_columns(tree) -> Dict[str, np.ndarray]:
     """Pytree → single-row columns: leaf path → (1, *shape) array."""
     out = {}
     for path, leaf in jax.tree_util.tree_leaves_with_path(tree):
-        arr = np.asarray(leaf)
-        out[_path_str(path)] = arr[None, ...]
+        out[_path_str(path)] = host_copy(leaf)[None, ...]
     return out
 
 
@@ -163,8 +178,8 @@ class CheckpointManager:
     def submit(self, *, step: int, params, opt_state=None,
                extra_meta: Optional[dict] = None):
         # synchronous part: pull to host memory (jax arrays → np)
-        params_host = jax.tree.map(np.asarray, params)
-        opt_host = (jax.tree.map(np.asarray, opt_state)
+        params_host = jax.tree.map(host_copy, params)
+        opt_host = (jax.tree.map(host_copy, opt_state)
                     if opt_state is not None else None)
         self._queue.put((step, params_host, opt_host, extra_meta or {}))
 

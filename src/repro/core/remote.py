@@ -635,6 +635,10 @@ class RemoteStore:
         digest = sha256_hex(data)
         return self._call("put_object", digest=digest, data=data)["digest"]
 
+    def put_parts(self, parts: Sequence[bytes]) -> str:
+        # the wire frame carries one ``bytes`` value
+        return self.put(b"".join(parts))
+
     def get(self, digest: str) -> bytes:
         data = self._call("get_object", digest=digest)["data"]
         if sha256_hex(data) != digest:  # never trust the wire
@@ -960,6 +964,9 @@ class TieredStore:
     # ------------------------------------------------------------- objects
     def put(self, data: bytes) -> str:
         return self.local.put(data)
+
+    def put_parts(self, parts: Sequence[bytes]) -> str:
+        return self.local.put_parts(parts)
 
     def get(self, digest: str) -> bytes:
         try:

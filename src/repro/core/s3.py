@@ -322,6 +322,10 @@ class S3Backend:
         self._upload(_object_key(digest), self._encode(data), digest)
         return digest
 
+    def put_parts(self, parts: Sequence[bytes]) -> str:
+        # one PUT carries one body
+        return self.put(b"".join(parts))
+
     def get(self, digest: str) -> bytes:
         data = decode_frame(self.get_encoded(digest),
                             what=f"object {digest}")

@@ -148,6 +148,10 @@ class StoreBackend(Protocol):
 
     # objects -----------------------------------------------------------
     def put(self, data: bytes) -> str: ...
+    # ``put`` of ``b"".join(parts)`` (same digest, same stored content),
+    # for a caller whose content is already a sequence of buffers: the
+    # filesystem store hashes, compresses and writes them without joining
+    def put_parts(self, parts: Sequence[bytes]) -> str: ...
     def get(self, digest: str) -> bytes: ...
     def has(self, digest: str) -> bool: ...
     def has_many(self, digests: Iterable[str]) -> Set[str]: ...
@@ -330,14 +334,23 @@ class ObjectStore:
                                          zstd.ZstdDecompressor())
         return ctx
 
-    def _encode(self, data: bytes) -> bytes:
-        if not self.compress or len(data) <= 64 or self.codec == "raw":
-            return _MAGIC + _CODEC_RAW + data
+    def _encode(self, parts: Sequence, n: int) -> List:
+        """The at-rest payload of content ``parts`` (``n`` bytes in all),
+        as a list of buffers: the frame header, then the parts themselves
+        (raw) or the codec's streamed output."""
+        if not self.compress or n <= 64 or self.codec == "raw":
+            return [_MAGIC + _CODEC_RAW, *parts]
         if self.codec == "zstd":
-            return _MAGIC + _CODEC_ZSTD + self._zstd()[0].compress(data)
-        # zstd levels reach 22 but zlib's cap is 9 — clamp so a store tuned
-        # for zstd keeps working on a host that falls back to zlib
-        return _MAGIC + _CODEC_ZLIB + zlib.compress(data, min(self.level, 9))
+            # the frame records the content size: ``_decode``'s one-shot
+            # decompress needs it
+            c = self._zstd()[0].compressobj(size=n)
+            head = _MAGIC + _CODEC_ZSTD
+        else:
+            # zstd levels reach 22 but zlib's cap is 9 — clamp so a store
+            # tuned for zstd keeps working on a host that falls back to zlib
+            c = zlib.compressobj(min(self.level, 9))
+            head = _MAGIC + _CODEC_ZLIB
+        return [head, *(c.compress(p) for p in parts), c.flush()]
 
     def _decode(self, digest: str, payload: bytes) -> bytes:
         if payload[4:5] == _CODEC_ZSTD and zstd is not None:
@@ -348,24 +361,35 @@ class ObjectStore:
         return decode_frame(payload, what=f"object {digest}")
 
     def put(self, data: bytes) -> str:
-        with tracing.span("store.hash", bytes=len(data)) as s:
-            digest = sha256_hex(data)
+        return self.put_parts((data,))
+
+    def put_parts(self, parts: Sequence) -> str:
+        """Store the content ``b"".join(parts)`` without joining it: the
+        parts are hashed, compressed and written in turn.  The digest and
+        the stored content are those of ``put`` of the joined bytes."""
+        n = sum(memoryview(p).nbytes for p in parts)
+        with tracing.span("store.hash", bytes=n) as s:
+            h = hashlib.sha256()
+            for p in parts:
+                h.update(p)
+            digest = h.hexdigest()
             path = self._path(digest)
             # dedup: content addressing makes re-puts free
             dedup = path.exists()
             s.set(dedup=dedup)
         if dedup:
             return digest
-        with tracing.span("store.compress", bytes=len(data)) as s:
-            payload = self._encode(data)
-            s.set(bytes_out=len(payload))
-        with tracing.span("store.write", bytes=len(payload)):
+        with tracing.span("store.compress", bytes=n) as s:
+            payload = self._encode(parts, n)
+            size = sum(memoryview(p).nbytes for p in payload)
+            s.set(bytes_out=size)
+        with tracing.span("store.write", bytes=size):
             path.parent.mkdir(parents=True, exist_ok=True)
             # Write-then-rename so readers never observe partial objects.
             fd, tmp = tempfile.mkstemp(dir=path.parent, prefix=".tmp-")
             try:
                 with os.fdopen(fd, "wb") as f:
-                    f.write(payload)
+                    f.writelines(payload)
                 os.replace(tmp, path)
             finally:
                 if os.path.exists(tmp):

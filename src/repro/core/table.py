@@ -334,8 +334,8 @@ class TableIO:
 
         entries: List[ManifestEntry] = []
         for chunk in _row_chunks(cols, self.target_rows_per_file):
-            blob, meta = tensorfile.encode(chunk)
-            digest = self.store.put(blob)
+            parts, meta = tensorfile.encode_parts(chunk)
+            digest = self.store.put_parts(parts)
             chunk_schema = Schema.from_obj(meta["schema"])
             if schema is None:
                 schema = chunk_schema

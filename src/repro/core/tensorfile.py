@@ -88,56 +88,118 @@ class Schema:
         return Schema(tuple(c for c in self.columns if c.name in keep))
 
 
+#: elements per slice of the NaN-counting pass (its temporaries stay small)
+_NAN_CHUNK = 1 << 20
+
+
 def _column_stats(arr: np.ndarray) -> Dict[str, Any]:
     """Min/max/nan-count — the Iceberg-style manifest stats used for pruning
-    and for cheap audit expectations."""
+    and for cheap audit expectations.
+
+    Floats are reduced in their own dtype, with no copy: every value
+    converts exactly to float64, so the extremes are the ones a float64
+    copy gives.  Only a zero's sign can depend on the order of a
+    reduction, and the stats are packed into content-addressed manifests:
+    where both -0.0 and 0.0 are present and a zero is an extreme, the
+    float64 copy decides, as it did for every float column before."""
     if arr.size == 0 or arr.dtype.kind not in "fiub":
         return {}
-    farr = arr.astype(np.float64) if arr.dtype.kind == "f" else arr
-    stats: Dict[str, Any] = {}
-    if arr.dtype.kind == "f":
-        nan_count = int(np.isnan(farr).sum())
-        stats["nan_count"] = nan_count
-        if nan_count < farr.size:
-            stats["min"] = float(np.nanmin(farr))
-            stats["max"] = float(np.nanmax(farr))
+    if arr.dtype.kind != "f":
+        return {"min": int(arr.min()), "max": int(arr.max())}
+    flat = arr.reshape(-1)
+    lo = flat.min()  # NaN propagates: a number here means no NaN
+    nan_count = 0
+    if np.isnan(lo):
+        nan_count = sum(int(np.count_nonzero(np.isnan(flat[i:i + _NAN_CHUNK])))
+                        for i in range(0, flat.size, _NAN_CHUNK))
+        if nan_count == flat.size:
+            return {"nan_count": nan_count}
+        with np.errstate(invalid="ignore"):  # ml_dtypes' fmin warns on NaN
+            lo, hi = np.fmin.reduce(flat), np.fmax.reduce(flat)
     else:
-        stats["min"] = int(arr.min()) if arr.dtype.kind in "iu" else int(arr.min())
-        stats["max"] = int(arr.max()) if arr.dtype.kind in "iu" else int(arr.max())
-    return stats
+        hi = flat.max()
+    if (lo == 0 or hi == 0) and _both_zero_signs(flat):
+        wide = arr.astype(np.float64)
+        lo, hi = np.nanmin(wide), np.nanmax(wide)
+    return {"nan_count": nan_count, "min": float(lo), "max": float(hi)}
 
 
-def encode(cols: Mapping[str, np.ndarray]) -> Tuple[bytes, Dict[str, Any]]:
-    """Serialize columns → (bytes, meta).  meta carries nrows/schema/stats and
-    becomes the manifest entry next to the content digest."""
+def _both_zero_signs(flat: np.ndarray) -> bool:
+    """Whether a float column holds both 0.0 and -0.0.  Read as unsigned
+    integers 0.0 is the least value; read as signed, -0.0 is.  A width
+    with no integer view answers yes."""
+    size = flat.dtype.itemsize
+    if size not in (1, 2, 4, 8):
+        return True
+    signed = flat.view(f"i{size}")
+    return bool(flat.view(f"u{size}").min() == 0
+                and signed.min() == np.iinfo(signed.dtype).min)
+
+
+def _bin_header(n: int) -> bytes:
+    """msgpack's bin8/bin16/bin32 header for ``n`` bytes."""
+    if n < 1 << 8:
+        return b"\xc4" + n.to_bytes(1, "big")
+    if n < 1 << 16:
+        return b"\xc5" + n.to_bytes(2, "big")
+    if n < 1 << 32:
+        return b"\xc6" + n.to_bytes(4, "big")
+    raise SchemaError(f"column of {n} bytes exceeds msgpack's 4 GiB bin")
+
+
+def encode_parts(cols: Mapping[str, np.ndarray]
+                 ) -> Tuple[List[Any], Dict[str, Any]]:
+    """Serialize columns → (parts, meta) without copying the columns.
+
+    ``b"".join(parts)`` is byte for byte the blob :func:`encode` returns:
+    the same msgpack map, with each column's ``bin`` header written here
+    and its data a view of the column's own memory (a column that is not
+    C-contiguous is copied once, and counted in the span's ``copied``).
+    The store hashes, compresses and writes the parts as they are
+    (``put_parts``).  meta carries nrows/schema/stats and becomes the
+    manifest entry next to the content digest."""
     if not cols:
         raise SchemaError("tensorfile needs at least one column")
     with tracing.span("table.encode") as s:
-        arrays = {k: np.ascontiguousarray(np.asarray(v))
-                  for k, v in cols.items()}
+        arrays = {k: np.asarray(v) for k, v in cols.items()}
+        copied = sum(a.nbytes for a in arrays.values()
+                     if not a.flags.c_contiguous)
+        arrays = {k: np.ascontiguousarray(a) for k, a in arrays.items()}
         nrows = {v.shape[0] for v in arrays.values()}
         if len(nrows) != 1:
             raise SchemaError(f"ragged columns: row counts {sorted(nrows)}")
         (n,) = nrows
         schema = Schema.of(arrays)
         with tracing.span("table.pack"):
-            payload = {
-                "v": _FORMAT_VERSION,
-                "nrows": n,
-                "schema": schema.to_obj(),
-                "data": {k: arrays[k].tobytes() for k in sorted(arrays)},
-            }
-            blob = msgpack.packb(payload, use_bin_type=True)
-        with tracing.span("table.stats"):
+            packer = msgpack.Packer(use_bin_type=True)
+            head = [packer.pack_map_header(4),
+                    packer.pack("v"), packer.pack(_FORMAT_VERSION),
+                    packer.pack("nrows"), packer.pack(n),
+                    packer.pack("schema"), packer.pack(schema.to_obj()),
+                    packer.pack("data"), packer.pack_map_header(len(arrays))]
+            parts: List[Any] = [b"".join(head)]
+            for k in sorted(arrays):
+                data = memoryview(arrays[k].reshape(-1).view(np.uint8))
+                parts.append(packer.pack(k) + _bin_header(data.nbytes))
+                parts.append(data)
+        with tracing.span("table.stats") as st:
             stats = {k: _column_stats(arrays[k]) for k in sorted(arrays)}
+            st.set(nan_columns=sum(v.get("nan_count", 0) > 0
+                                   for v in stats.values()))
         meta = {
             "nrows": n,
             "schema": schema.to_obj(),
             "stats": stats,
             "nbytes": sum(a.nbytes for a in arrays.values()),
         }
-        s.set(bytes=len(blob))
-    return blob, meta
+        s.set(bytes=sum(memoryview(p).nbytes for p in parts), copied=copied)
+    return parts, meta
+
+
+def encode(cols: Mapping[str, np.ndarray]) -> Tuple[bytes, Dict[str, Any]]:
+    """:func:`encode_parts` joined into one ``bytes`` blob."""
+    parts, meta = encode_parts(cols)
+    return b"".join(parts), meta
 
 
 def decode(blob: bytes, columns: Optional[Sequence[str]] = None

@@ -214,12 +214,16 @@ class SeededSchedule(Schedule):
 #: StoreBackend methods wrapped with sync points.  Anything not listed
 #: (root, _supports_encoded, gc_mark, ...) passes through untouched.
 INTERCEPTED_OPS = (
-    "put", "put_many", "put_encoded", "put_many_encoded",
+    "put", "put_parts", "put_many", "put_encoded", "put_many_encoded",
     "get", "get_many", "get_encoded", "get_many_encoded",
     "has", "has_many", "size", "mtime", "delete_object",
     "set_ref", "get_ref", "cas_ref", "cas_refs", "delete_ref",
     "list_refs", "list_objects",
 )
+#: ops that fire another op's sync points: ``put_parts`` is ``put`` of the
+#: joined parts, so a seed names the same fault pattern whichever of the
+#: two a caller uses
+SYNC_POINT_OF = {"put_parts": "put"}
 
 
 class FaultyStore:
@@ -240,7 +244,8 @@ class FaultyStore:
         if name in INTERCEPTED_OPS and callable(attr):
             schedule = self.schedule
 
-            def wrapped(*args, _attr=attr, _name=name, **kwargs):
+            def wrapped(*args, _attr=attr,
+                        _name=SYNC_POINT_OF.get(name, name), **kwargs):
                 schedule.fire(f"{_name}:before")
                 out = _attr(*args, **kwargs)
                 schedule.fire(f"{_name}:after")

@@ -28,6 +28,54 @@ def test_leaves_columns_roundtrip():
     np.testing.assert_array_equal(back["b"]["c"], tree["b"]["c"])
 
 
+def _transposed_layout(x):
+    """``x`` held on the device with its last two axes swapped in memory,
+    as a TPU holds leaves whose last axis would pad its tiles most."""
+    from jax.experimental.layout import Format, Layout
+
+    order = (*range(x.ndim - 2), x.ndim - 1, x.ndim - 2)
+    y = jax.device_put(x, Format(Layout(major_to_minor=order), x.sharding))
+    assert not np.asarray(y).flags.c_contiguous
+    return y
+
+
+@pytest.mark.parametrize("shape", [(48, 32), (4, 16, 40)])
+def test_commit_path_copies_no_column_on_the_host(lake, shape):
+    """Leaves in a transposed device layout reach the tensorfile in row
+    order: every ``table.encode`` of the commit reports ``copied`` 0."""
+    from repro import tracing
+
+    x = jnp.arange(np.prod(shape), dtype=jnp.float32).reshape(shape)
+    params = {"w": _transposed_layout(x), "b": jnp.ones(3, jnp.bfloat16)}
+    opt = {"mu": _transposed_layout(x * 2)}
+    lake.catalog.create_branch("t.run", "main", author="t")
+    commit = save(lake, "t.run", step=1, params=params, opt_state=opt,
+                  author="t")
+    (root,) = [s for s in tracing.spans() if s.name == "ckpt.save"][-1:]
+    encodes = [s for s in tracing.spans()
+               if s.name == "table.encode" and s.t0 >= root.t0
+               and s.t1 <= root.t1]
+    assert [s.attrs["copied"] for s in encodes] == [0, 0]
+    p2, opt2, _ = restore(lake, commit)
+    np.testing.assert_array_equal(np.asarray(p2["w"]), np.asarray(x))
+    np.testing.assert_array_equal(opt2["mu"], np.asarray(x * 2))
+
+
+def test_async_manager_host_copies_are_row_major(lake):
+    lake.catalog.create_branch("t.run", "main", author="t")
+    x = jnp.arange(48 * 32, dtype=jnp.float32).reshape(48, 32)
+    mgr = CheckpointManager(lake, "t.run", author="t")
+    mgr.submit(step=1, params={"w": _transposed_layout(x)})
+    ((_, commit),) = mgr.wait()
+    mgr.close()
+    from repro import tracing
+
+    encoded = [s for s in tracing.spans() if s.name == "table.encode"][-1]
+    assert encoded.attrs["copied"] == 0
+    p2, _, _ = restore(lake, commit)
+    np.testing.assert_array_equal(np.asarray(p2["w"]), np.asarray(x))
+
+
 def test_save_restore_roundtrip(lake):
     params = _params()
     opt = adamw.init(params, adamw.AdamWConfig())
