@@ -4,9 +4,40 @@ Model FLOPs count each multiply-add as two operations and leave out what a
 program recomputes (rematerialization) or computes and throws away (masked
 halves, padded rows, free serving slots).  A training step is the forward
 pass and twice it for the backward.
+
+These counts hold for the layers this file knows.  A configuration whose
+work they miscount (experts, a layer pattern) names a module of its own
+under ``"work"`` in its file, and the per-layer readers count through
+``of(rec)``: that module is this file with the module's definitions laid
+over it, so a name it leaves out is this file's, and a helper it
+overrides is the one this file's functions call too.
 """
 
 from __future__ import annotations
+
+import os
+import sys
+import types
+
+_WORK: dict = {}
+
+
+def of(rec):
+    """The counts for a run's record: its configuration's ``work`` module
+    (a path relative to this directory, or absolute), else this module."""
+    path = rec.cell.config.get("work")
+    if path is None:
+        return sys.modules[__name__]
+    here = os.path.dirname(os.path.abspath(__file__))
+    path = os.path.normpath(os.path.join(here, path))
+    if path not in _WORK:
+        mod = types.ModuleType("work_" + os.path.basename(path)[:-3])
+        mod.__file__ = path
+        for src in (os.path.abspath(__file__), path):
+            with open(src) as f:
+                exec(compile(f.read(), src, "exec"), mod.__dict__)
+        _WORK[path] = mod
+    return _WORK[path]
 
 
 def _attn_layer_matmul_params(cfg) -> int:

@@ -17,8 +17,9 @@ def read(rec):
         return None
     bounds = {"compute": 0, "memory": 0}
     least = 0.0
+    work = flops.of(rec)
     for live in steps:
-        w = flops.decode_interval(rec.cfg, live)
+        w = work.decode_interval(rec.cfg, live)
         t, bound = flops.roofline_s(w["flops"], w["bytes"], rec.peaks)
         least += t
         bounds[bound] += 1

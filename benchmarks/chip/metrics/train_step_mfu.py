@@ -11,5 +11,5 @@ def read(rec):
         return None
     t = sum(e.end - e.start for e in steps) / len(steps) / 1e9
     c = rec.counters
-    work = flops.train_step_flops(rec.cfg, c["batch"], c["seq_len"])
+    work = flops.of(rec).train_step_flops(rec.cfg, c["batch"], c["seq_len"])
     return 100.0 * work / (t * rec.peaks["bf16_flops"])

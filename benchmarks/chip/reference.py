@@ -2,7 +2,28 @@
 step in straightforward ``jax.numpy`` at float32, matrix products at
 ``highest`` precision, with no cache, batching, chunking or kernel.
 
-It imports nothing of the program.  Its sizes come from the configuration
+This module is the reference of every configuration file that names no
+other (``"reference"``, see ``spec.py``); it reads two kinds, Mamba-2
+and dense attention with a gated MLP.  A configuration of another
+architecture brings its own module under ``references/``, which may
+import this one, and meets the same contract:
+
+  serving (``serve.compare``)
+    ``Sizes.from_file(config_file)``: the sizes, from the configuration
+    file as a dict; it raises on a file it cannot read
+    ``init_weights(sz, seed)``: the weights the program draws from that
+    seed, drawn here by the initialization the program documents
+    ``forward_logits(sz, w, tokens, pad_to, dtype="f32")``: float32
+    logits of every position; ``dtype="fp8"`` is the control
+  training (``train.compare``)
+    ``train_readings(sz, seed, batches, job, dtype="f32", frozen=False)``
+    -> ``{"losses", "first_grad", "raw_grad", "change"}``, the norms per
+    leaf under the program's leaf names.  The controls call it with
+    ``dtype="fp8"``, with ``frozen=True`` (a step that returns its state
+    unchanged) and on the first half of each batch.
+
+A reference imports nothing of the program and takes nothing the program
+made: no weights, scales or tables.  Its sizes come from the configuration
 file and its weights from the seed, by the initialization the program
 documents (one key split 64 ways, drawn in the order of the parameter
 tree, each weight a normal draw times its scale, stored in bfloat16), so
@@ -21,16 +42,24 @@ from __future__ import annotations
 
 import dataclasses
 import functools
+import os
 from typing import Dict, List
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 
-from spec import padded_vocab
+from spec import FIELDS, HERE, padded_vocab
 
 F32 = jnp.float32
 NORM_EPS = 1e-6
+#: the configuration keys each kind reads
+KEYS = {"ssm": ("n_layer", "d_model", "vocab_size", "d_state", "expand",
+                "headdim", "d_conv", "torch_dtype"),
+        "dense": ("num_hidden_layers", "hidden_size", "vocab_size",
+                  "num_attention_heads", "num_key_value_heads", "head_dim",
+                  "intermediate_size", "rope_theta", "rms_norm_eps",
+                  "torch_dtype")}
 
 
 @dataclasses.dataclass(frozen=True)
@@ -61,8 +90,25 @@ class Sizes:
 
     @classmethod
     def from_file(cls, config_file: dict) -> "Sizes":
+        """The sizes of a Mamba-2 file (one with ``n_layer``) or a dense
+        one; a file that names another reference, maps a key this module
+        reads to another field, or lacks a key of its kind is an error."""
+        name = config_file.get("name")
+        named = os.path.normpath(os.path.join(
+            HERE, config_file.get("reference", "reference.py")))
+        if named != os.path.abspath(__file__):
+            raise ValueError(f"{name}: its reference is {named}, not this one")
         c = config_file["config"]
-        if "n_layer" in c:  # Mamba-2
+        kind = "ssm" if "n_layer" in c else "dense"
+        remapped = sorted(k for k, f in config_file.get("fields", {}).items()
+                          if k in KEYS[kind] and FIELDS.get(k) != f)
+        missing = [k for k in KEYS[kind] if k not in c]
+        faults = ([f"its fields remap {remapped}"] if remapped else []) + \
+            ([f"it lacks {missing}"] if missing else [])
+        if faults:
+            raise ValueError(f"{name}: read as {kind} layers, but "
+                             + " and ".join(faults))
+        if kind == "ssm":
             return cls(kind="ssm", n_layers=c["n_layer"], d_model=c["d_model"],
                        vocab=padded_vocab(c), d_state=c["d_state"],
                        expand=c["expand"], headdim=c["headdim"],

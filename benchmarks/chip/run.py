@@ -20,7 +20,6 @@ import time
 T_START = time.perf_counter()
 
 import argparse  # noqa: E402
-import importlib.util  # noqa: E402
 import json  # noqa: E402
 import os  # noqa: E402
 import sys  # noqa: E402
@@ -40,12 +39,7 @@ EXIT_NO_CHIP = 3
 
 
 def load_metric(name: str):
-    path = os.path.join(HERE, "metrics", name + ".py")
-    mod_spec = importlib.util.spec_from_file_location(
-        "metric_" + name.replace(".", "_"), path)
-    mod = importlib.util.module_from_spec(mod_spec)
-    mod_spec.loader.exec_module(mod)
-    return mod.read
+    return spec.load_module(os.path.join("metrics", name + ".py")).read
 
 
 def limits_of(cell_name: str) -> dict:

@@ -29,7 +29,6 @@ import numpy as np
 
 import generators
 import harness
-import reference
 from harness import Check, log
 
 TRACE_SPANS = ("admit", "sync", "idle_wait")
@@ -199,11 +198,12 @@ def choose_sample(server, flows, n: int, seed: int):
             for r in pick]
 
 
-def gaps_of(sz, w, sample, max_len: int, dtypes=("f32",)):
+def gaps_of(reference, sz, w, sample, max_len: int, dtypes=("f32",)):
     """Per dtype: the widest float32-reference gap (best logit minus the
     chosen token's) over every served position of the sample.  For "f32"
     the chosen token is the served one; for another dtype it is the token
-    that dtype's own forward puts first (the control's reading)."""
+    that dtype's own forward puts first (the control's reading).
+    ``reference`` is the configuration's reference module."""
     out = {d: 0.0 for d in dtypes}
     for prompt, served in sample:
         seq = np.concatenate([prompt, served[:-1]]).astype(np.int32)
@@ -225,10 +225,11 @@ def gaps_of(sz, w, sample, max_len: int, dtypes=("f32",)):
 def compare(cell, init_seed, sample, limits, dtypes=("f32",)) -> List[Check]:
     if not sample:
         return [Check("served_gap", float("inf"), limits["served_gap"])]
+    reference = cell.reference()
     sz = reference.Sizes.from_file(cell.config)
     t0 = time.perf_counter()
     w = reference.init_weights(sz, init_seed)
-    gaps = gaps_of(sz, w, sample, cell.traffic["max_len"], dtypes)
+    gaps = gaps_of(reference, sz, w, sample, cell.traffic["max_len"], dtypes)
     n_tok = sum(len(s) for _, s in sample)
     log(f"reference: {len(sample)} requests, {n_tok} served tokens, "
         f"{time.perf_counter() - t0:.1f} s; widest gaps {gaps}")

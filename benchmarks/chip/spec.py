@@ -5,15 +5,31 @@ Everything that belongs to one configuration, one traffic mix or one
 per-layer metric lives in a file of its own under this directory:
 
   configs/<config>.json   the configuration as it is run
+  references/<config>.py  its plain reference, where ``reference.py``
+                          does not cover it
+  work/<config>.py        its operations and bytes, where ``flops.py``
+                          does not count them right
+  tests/tiny/<config>.json  its form for the CPU tests
   traffic/<traffic>.json  the traffic mix or training job
   metrics/<metric>.py     the reduction of one per-layer metric
 
-so a later cell adds files and entries and edits none.
+so a later cell, of a new architecture too, adds files and entries and
+edits none.  A configuration file names its own pieces:
+
+  "reference": "references/<config>.py"   (default ``reference.py``)
+  "work": "work/<config>.py"              (default ``flops.py``)
+  "fields": {"<config key>": "<ModelConfig field>", ...}
+
+paths relative to this directory (or absolute).  ``fields`` is laid over
+``FIELDS`` for that file alone: a file whose ``intermediate_size`` is the
+expert width maps it with ``{"intermediate_size": "expert_d_ff"}``.
 """
 
 from __future__ import annotations
 
 import dataclasses
+import importlib
+import importlib.util
 import json
 import os
 import sys
@@ -45,6 +61,28 @@ def padded_vocab(c: dict) -> int:
     return -(-int(c["vocab_size"]) // m) * m
 
 
+_MODULES: dict = {}
+
+
+def load_module(path: str):
+    """The Python file at ``path`` (relative to this directory, or
+    absolute) as a module, loaded once per path; a file of this directory
+    itself is its module as imported."""
+    path = os.path.normpath(os.path.join(HERE, path))
+    if path not in _MODULES:
+        stem = os.path.splitext(os.path.relpath(path, HERE))[0]
+        if os.path.dirname(path) == HERE:
+            _MODULES[path] = importlib.import_module(stem)
+        else:
+            mod_spec = importlib.util.spec_from_file_location(
+                "bench_" + "".join(c if c.isalnum() else "_" for c in stem),
+                path)
+            mod = importlib.util.module_from_spec(mod_spec)
+            mod_spec.loader.exec_module(mod)
+            _MODULES[path] = mod
+    return _MODULES[path]
+
+
 def _read_json(path: str) -> dict:
     with open(path) as f:
         return json.load(f)
@@ -62,21 +100,39 @@ class Cell:
     traffic: dict         # traffic/<traffic>.json
     end_to_end: list      # BENCHMARK.json metrics this cell reports
     per_layer: list
+    config_file: str = ""  # where ``config`` was read, for messages
 
     def model_config(self):
         """The program's configuration for this cell: its registry entry
         (the smoke preset where the file says so) with the sizes the
-        configuration file states applied over it."""
+        configuration file states applied over it, each key through the
+        file's ``fields`` or else ``FIELDS``."""
         if SRC not in sys.path:
             sys.path.insert(0, SRC)
         from repro.configs import full_config, smoke_config
+        from repro.models.config import ModelConfig
 
         preset = smoke_config if self.config.get("preset") == "smoke" \
             else full_config
-        want = {FIELDS[k]: v for k, v in self.config["config"].items()
-                if k in FIELDS}
+        fields = {**FIELDS, **self.config.get("fields", {})}
+        known = {f.name for f in dataclasses.fields(ModelConfig)}
+        want = {}
+        for k, v in self.config["config"].items():
+            if k not in fields:
+                continue
+            if fields[k] not in known:
+                raise KeyError(
+                    f"{self.config_file or self.config.get('name')}: key "
+                    f"{k!r} maps to {fields[k]!r}, which ModelConfig lacks")
+            # ModelConfig is frozen and hashed (the engine caches on it)
+            want[fields[k]] = tuple(v) if isinstance(v, list) else v
         want["vocab_size"] = padded_vocab(self.config["config"])
         return preset(self.config["arch"]).with_(**want)
+
+    def reference(self):
+        """The plain reference module this configuration is compared with:
+        the file its ``reference`` names, else ``reference.py``."""
+        return load_module(self.config.get("reference", "reference.py"))
 
 
 def _reports(metric: dict, cell: str) -> bool:
@@ -90,9 +146,11 @@ def load_cell(name: str, bench: dict | None = None) -> Cell:
         raise KeyError(f"unknown workload {name!r}; known: {sorted(cells)}")
     w = cells[name]
     configs = {c["name"]: c for c in bench["configs"]}
-    config = _read_json(os.path.join(ROOT, configs[w["config"]]["file"]))
+    config_file = os.path.join(ROOT, configs[w["config"]]["file"])
     traffic = _read_json(os.path.join(HERE, "traffic", w["traffic"] + ".json"))
     return Cell(
-        name=name, chips=int(w["chips"]), config=config, traffic=traffic,
+        name=name, chips=int(w["chips"]), config=_read_json(config_file),
+        traffic=traffic,
         end_to_end=[m for m in bench["end_to_end"] if _reports(m, name)],
-        per_layer=[m for m in bench["per_layer"] if _reports(m, name)])
+        per_layer=[m for m in bench["per_layer"] if _reports(m, name)],
+        config_file=config_file)

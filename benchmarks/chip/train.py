@@ -8,9 +8,9 @@ close), its loader's ``batch`` (the feed's host span) and
 
   set-up   lake, corpus, packing pipeline, Trainer; steps 0 .. setup-1
            through the window's own call and feed (step 0 compiles or
-           loads the compiled step); the commit's digest is warmed once
-           where the set-up steps' pace puts a commit within reach of
-           the window
+           loads the compiled step); the commit's digest and its
+           on-device relayout are warmed once where the set-up steps'
+           pace puts a commit within reach of the window
   window   opens at call ``setup_steps`` + 1 of ``train_step`` and closes
            at the first call after ``--seconds``: a commit that began in
            the window counts in full
@@ -32,7 +32,6 @@ import numpy as np
 
 import generators
 import harness
-import reference
 from harness import Check, log
 
 TRACE_SPANS = ("feed", "commit")
@@ -70,6 +69,37 @@ def commits_in(first: int, n: int, every: int) -> int:
     first + n - 1 (it commits after step s when s + 1 is a multiple of
     ``every``)."""
     return (first + n) // every - first // every
+
+
+def _warm_relayout(tree) -> None:
+    """Compile (or load) the on-device relayout that the commit's host copy
+    gives a leaf the TPU holds in another axis order
+    (``repro.checkpoint.saver.host_copy``), once per kind of leaf, so that
+    the window's first commit compiles nothing."""
+    import jax
+    from jax.experimental.layout import Format, Layout
+
+    seen = set()
+    for leaf in jax.tree.leaves(tree):
+        layout = getattr(getattr(leaf, "format", None), "layout", None)
+        order = tuple(range(leaf.ndim))
+        kind = (leaf.shape, leaf.dtype, layout)
+        if layout is None or layout.major_to_minor == order or kind in seen:
+            continue
+        seen.add(kind)
+        jax.device_put(leaf, Format(Layout(major_to_minor=order),
+                                    leaf.sharding)).block_until_ready()
+
+
+def _compiled_in(t0: float, t1: float) -> List[str]:
+    """Names of the functions the program's spans saw compile or load from
+    the cache between t0 and t1."""
+    try:
+        from repro import tracing
+    except ImportError:
+        return []
+    return [s.attrs.get("fun_name", "?") for s in tracing.spans()
+            if s.name == tracing.COMPILE and t0 <= s.t1 <= t1]
 
 
 def _change_norms(master, params0) -> Dict[str, float]:
@@ -143,12 +173,14 @@ def run(cell, seed: int, seconds: float, profiler, spans, compiles,
                                            1.0 / (1.0 - w.b1))
             if w.calls == w.setup_steps + 1:
                 # a commit within twice the window's reach at the set-up
-                # steps' pace: its digest programs are loaded now
+                # steps' pace: its digest and relayout programs are
+                # loaded now
                 walls = [r.wall_ms for r in trainer.records[1:]] or \
                     [r.wall_ms for r in trainer.records]
                 reach = int(2 * w.seconds / (min(walls) / 1e3)) + 1
                 if commits_in(w.setup_steps, reach, job["ckpt_every"]):
                     tree_digest_hex(params)
+                    _warm_relayout((params, opt_state))
                 master = (opt_state.master if opt_state.master is not None
                           else params)
                 w.change = _change_norms(master, w.params0)
@@ -199,7 +231,8 @@ def run(cell, seed: int, seconds: float, profiler, spans, compiles,
         log(f"setup {e2e['setup_s']:.2f} s; window: {steps} steps, "
             f"{len(w.commits)} commits, "
             f"{w.t1 - w.t0:.3f} s; compiles in the window: "
-            f"{compiles.between(w.t0, w.t1)}; losses "
+            f"{compiles.between(w.t0, w.t1)} "
+            f"{_compiled_in(w.t0, w.t1)}; losses "
             f"{[r.loss for r in trainer.records[:w.setup_steps]]}")
 
         committed = _read_commit(lake, w.commits[0]) if w.commits else None
@@ -289,6 +322,7 @@ def compare(cell, init_seed, docs, w: Watch, losses, limits) -> List[Check]:
     if err:
         log(f"check: {err}")
         return [Check("fed_rows", 1.0, 0.0)]
+    reference = cell.reference()
     sz = reference.Sizes.from_file(cell.config)
     t0 = time.perf_counter()
     ref = reference.train_readings(sz, init_seed, batches, job)
