@@ -199,6 +199,178 @@ def test_put_parts_same_digest_on_every_backend(tmp_path, backend):
             httpd.shutdown()
 
 
+# ------------------------------------------- zstd's multi-threaded mode
+needs_zstd = pytest.mark.skipif(store_mod.zstd is None,
+                                reason="zstandard is not installed")
+#: one and a half times the multi-threaded cut-off: a frame of several jobs
+LARGE = store_mod.ZSTD_MT_MIN_BYTES * 3 // 2 + 12
+SMALL = 1 << 20  # where single- and multi-threaded frames already differ
+
+
+def _floats(n):
+    """``n`` bytes of float32 noise, like a checkpoint's state."""
+    rng = np.random.default_rng(n)
+    return rng.standard_normal(n // 4, dtype=np.float32).tobytes()
+
+
+@pytest.fixture(scope="module")
+def large():
+    return _floats(LARGE)
+
+
+def _frame(data, threads):
+    """The zstd payload of ``data`` made by libzstd directly, streamed; a
+    multi-threaded frame flushes its jobs before its end."""
+    zstd = store_mod.zstd
+    c = zstd.ZstdCompressor(level=3, threads=threads).compressobj(
+        size=len(data))
+    out = b"RPR1\x01" + c.compress(data)
+    if threads:
+        out += c.flush(zstd.COMPRESSOBJ_FLUSH_BLOCK)
+    return out + c.flush()
+
+
+def _parts(data):
+    """Parts of uneven sizes, as a tensorfile's column buffers come."""
+    cuts = [0, 3, 5 << 20, (5 << 20) + 1, 17 << 20, len(data)]
+    return [memoryview(data)[a:b] for a, b in zip(cuts, cuts[1:])]
+
+
+def _compress_span():
+    from repro import tracing
+
+    return [s for s in tracing.spans() if s.name == "store.compress"][-1]
+
+
+@needs_zstd
+@pytest.mark.parametrize("via", ["put_parts", "put_encoded"])
+def test_large_object_roundtrips(tmp_path, large, via):
+    """Above the cut-off the object reads back through ``get``, through
+    any zstd decoder, and after a copy of its payload to another store."""
+    store = ObjectStore(tmp_path / "a")
+    digest = store.put_parts(_parts(large))
+    assert digest == sha256_hex(large)
+    payload = store.get_encoded(digest)
+    frame = store_mod.zstd.get_frame_parameters(payload[5:])
+    assert frame.content_size == LARGE
+    assert store_mod.zstd.ZstdDecompressor().decompress(payload[5:]) == large
+    if via == "put_encoded":
+        store = ObjectStore(tmp_path / "b")
+        assert store.put_encoded(payload) == digest
+        assert store.get_encoded(digest) == payload
+    assert store.get(digest) == large
+
+
+@needs_zstd
+@pytest.mark.parametrize("cores", [1, 2, 4])
+@pytest.mark.parametrize("size", ["odd", "whole_jobs"])
+def test_large_payload_is_the_same_on_any_core_count(tmp_path, large,
+                                                     monkeypatch, cores,
+                                                     size):
+    """The frame is libzstd's multi-threaded one, byte for byte the same
+    whatever number of workers made it; the span names that number.
+    ``whole_jobs`` ends on a full libzstd job (8 MiB at level 3)."""
+    data = large if size == "odd" else _floats(2 * store_mod.ZSTD_MT_MIN_BYTES)
+    monkeypatch.setattr(store_mod, "usable_cores", lambda: cores)
+    store = ObjectStore(tmp_path)
+    digest = store.put_parts(_parts(data))
+    assert _compress_span().attrs["workers"] == cores
+    assert store.get_encoded(digest) == _frame(data, threads=1)
+
+
+@needs_zstd
+def test_workers_capped(monkeypatch):
+    monkeypatch.setattr(store_mod, "usable_cores", lambda: 1000)
+    assert store_mod.zstd_workers(LARGE) == store_mod.ZSTD_MT_MAX_WORKERS
+    assert store_mod.zstd_workers(store_mod.ZSTD_MT_MIN_BYTES - 1) == 0
+
+
+@needs_zstd
+def test_small_object_is_the_single_threaded_frame(tmp_path):
+    data = _floats(SMALL)
+    digest = ObjectStore(tmp_path).put(data)
+    assert _frame(data, threads=0) != _frame(data, threads=1)
+    assert ObjectStore(tmp_path).get_encoded(digest) == _frame(data, 0)
+
+
+@needs_zstd
+def test_large_single_threaded_frame_still_reads(tmp_path, large):
+    """A large object stored single-threaded, as before the cut-off
+    existed, reads back and keeps its payload."""
+    old = _frame(large, threads=0)
+    store = ObjectStore(tmp_path)
+    digest = store.put_encoded(old)
+    assert store.get(digest) == large
+    assert store.get_encoded(digest) == old
+    assert store.put(large) == digest  # dedup: the old payload stays
+    assert store.get_encoded(digest) == old
+
+
+@needs_zstd
+def test_threads_put_large_objects_at_once(tmp_path, large):
+    """Each writing thread compresses with a context of its own."""
+    import threading
+
+    store = ObjectStore(tmp_path)
+    blobs = [large, large[::-1]]
+    barrier = threading.Barrier(len(blobs))
+    digests = [None] * len(blobs)
+
+    def worker(i):
+        barrier.wait()
+        digests[i] = store.put_parts(_parts(blobs[i]))
+
+    threads = [threading.Thread(target=worker, args=(i,))
+               for i in range(len(blobs))]
+    for t in threads:
+        t.start()
+    for t in threads:
+        t.join()
+    for digest, blob in zip(digests, blobs):
+        assert digest == sha256_hex(blob)
+        assert store.get(digest) == blob
+        assert store.get_encoded(digest) == _frame(blob, threads=1)
+
+
+@needs_zstd
+@pytest.mark.parametrize("writer", ["encode_frame", "s3"])
+@pytest.mark.parametrize("size", ["small", "large"])
+def test_every_writer_stores_the_same_payload(tmp_path, large, writer, size):
+    """A payload's bytes depend on its content alone, whatever backend
+    wrote it."""
+    from repro.core import connect, serve_s3
+
+    data = large if size == "large" else _floats(SMALL)
+    digest = ObjectStore(tmp_path / "fs").put(data)
+    want = ObjectStore(tmp_path / "fs").get_encoded(digest)
+    if writer == "encode_frame":
+        assert store_mod.encode_frame(data) == want
+        return
+    httpd, url = serve_s3(tmp_path / "bucket")
+    try:
+        s3 = connect(url)
+        assert s3.put(data) == digest
+        assert s3.get_encoded(digest) == want
+        s3.close()
+    finally:
+        httpd.shutdown()
+
+
+@pytest.mark.parametrize("codec,size,threaded", [
+    pytest.param("zstd", "large", True, marks=needs_zstd),
+    pytest.param("zstd", "small", False, marks=needs_zstd),
+    ("zlib", "large", False),
+    ("raw", "large", False),
+])
+def test_compress_span_counts_workers(tmp_path, large, codec, size,
+                                      threaded):
+    """``workers`` is libzstd's worker count, 0 where no worker ran."""
+    data = large if size == "large" else _floats(SMALL)
+    ObjectStore(tmp_path, codec=codec).put(data)
+    workers = _compress_span().attrs["workers"]
+    assert workers >= 1 if threaded else workers == 0
+
+
 # ------------------------------------------------------------ ref namespaces
 def test_namespaced_refs_roundtrip(tmp_path):
     store = ObjectStore(tmp_path)
